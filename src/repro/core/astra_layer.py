@@ -41,7 +41,7 @@ class ComputeConfig:
     mode: str = "exact"
     x_gen: str = "thermometer"
     w_gen: str = "bresenham"
-    use_pallas: bool = False  # Pallas kernels (interpret on CPU) vs jnp refs
+    use_pallas: bool = False  # Pallas kernels (interpreted off-TPU) vs jnp refs
     act_scale: Optional[float] = None  # static activation scale (PTQ-calibrated)
 
     def __post_init__(self):

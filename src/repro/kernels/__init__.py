@@ -11,6 +11,25 @@ Each subpackage ships ``kernel.py`` (pl.pallas_call + BlockSpec),
 * ``paged_attention``— gather-free serve-engine decode/suffix-prefill over
   the paged KV pool (block tables as scalar-prefetch operands)
 
-Kernels target TPU (VMEM BlockSpecs, 128-aligned tiles) and are validated
-on CPU with ``interpret=True``.
+Kernels target TPU (VMEM BlockSpecs, 128-aligned tiles).  Interpret mode
+is chosen by backend (:func:`interpret_mode`): compiled on TPU,
+interpreted on CPU, where the tests validate each kernel against its
+``ref.py``.
 """
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """Whether a ``pallas_call`` runs interpreted, chosen by backend.
+
+    ``None`` (every wrapper's default) compiles on TPU and interprets
+    anywhere else; there is no fallback on TPU — a kernel that does not
+    compile fails the run.  An explicit bool is honoured as given.
+    """
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret

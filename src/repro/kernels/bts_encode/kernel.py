@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.bitstream import LFSR_ORDER
+from repro.kernels import interpret_mode
 
 STREAM_LEN = 128
 N_WORDS = 4
@@ -51,7 +52,7 @@ def _kernel(q_ref, order_ref, words_ref, sign_ref, *, generator):
 
 
 @functools.partial(jax.jit, static_argnames=("generator", "br", "bc", "interpret"))
-def bts_encode_kernel(q: jax.Array, *, generator="bresenham", br=64, bc=64, interpret=True):
+def bts_encode_kernel(q: jax.Array, *, generator="bresenham", br=64, bc=64, interpret=None):
     r, c = q.shape
     assert r % br == 0 and c % bc == 0
     kern = functools.partial(_kernel, generator=generator)
@@ -73,5 +74,5 @@ def bts_encode_kernel(q: jax.Array, *, generator="bresenham", br=64, bc=64, inte
             jax.ShapeDtypeStruct((r, c, N_WORDS), jnp.uint32),
             jax.ShapeDtypeStruct((r, c), jnp.int8),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, order)

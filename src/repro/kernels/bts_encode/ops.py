@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +11,8 @@ from repro.kernels.bts_encode.kernel import bts_encode_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("generator", "br", "bc", "interpret"))
-def bts_encode(q: jax.Array, generator: str = "bresenham", br: int = 64, bc: int = 64, interpret: bool = True):
+def bts_encode(q: jax.Array, generator: str = "bresenham", br: int = 64, bc: int = 64,
+               interpret: Optional[bool] = None):
     r, c = q.shape
     br, bc = min(br, r), min(bc, c)
     pr, pc = (-r) % br, (-c) % bc

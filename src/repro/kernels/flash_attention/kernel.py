@@ -20,10 +20,13 @@ d<=256 keep the working set ~0.5 MiB.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -94,7 +97,7 @@ def flash_attention_kernel(
     bk: int = 128,
     q_len: int = 0,  # GQA fold period: row r is query position r % q_len (0 = identity)
     softcap: float = 0.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     bh, sq, d = q.shape
     sk = k.shape[1]
@@ -119,6 +122,6 @@ def flash_attention_kernel(
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)
     return o / jnp.maximum(l, 1e-30), m, l

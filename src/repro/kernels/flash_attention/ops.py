@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,7 @@ def flash_attention(
     softcap: float = 0.0,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     b, hq, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]

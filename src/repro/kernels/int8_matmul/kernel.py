@@ -13,10 +13,13 @@ Grid = (M/bm, N/bn, K/bk), K innermost/sequential.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 
 def _kernel(x_ref, w_ref, o_ref):
@@ -42,7 +45,7 @@ def int8_matmul_kernel(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     m, k = x.shape
     k2, n = w.shape
@@ -56,5 +59,5 @@ def int8_matmul_kernel(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, w)

@@ -20,7 +20,7 @@ def _pad(a, mult, axis):
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def int8_matmul(xq: QTensor, wq: QTensor, *, bm=128, bn=128, bk=128, interpret=True) -> jax.Array:
+def int8_matmul(xq: QTensor, wq: QTensor, *, bm=128, bn=128, bk=128, interpret=None) -> jax.Array:
     m, k = xq.q.shape
     n = wq.q.shape[1]
     x = _pad(_pad(xq.q, bm, 0), bk, 1)

@@ -40,17 +40,20 @@ GQA is native: the grid runs over KV heads and each step computes all
 Logit softcap (``tanh(s/c)*c``) is applied pre-mask, matching ``_sdpa``.
 
 Kernels target TPU (VMEM blocks; pick ``bs``/``hd`` 128-aligned for MXU
-shapes) and are validated on CPU with ``interpret=True`` against
-``ref.py``.
+shapes): compiled there, interpreted elsewhere (``interpret_mode``), and
+validated on CPU against ``ref.py``.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -156,7 +159,7 @@ def paged_attention_kernel(
     causal: bool = False,
     q_len: int = 1,
     softcap: float = 0.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Streamed paged attention.  Returns un-normalized (o, m, l).
 
@@ -204,7 +207,7 @@ def paged_attention_kernel(
         (jnp.asarray(k_scale, jnp.float32), jnp.asarray(v_scale, jnp.float32))
         if quantized else ())
     return pl.pallas_call(
-        body, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
+        body, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret_mode(interpret),
     )(*operands, q, k_pool, v_pool)
 
 
@@ -218,7 +221,7 @@ def dense_attention_kernel(
     scale: float,
     bk: int = 128,
     softcap: float = 0.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Length-masked single-query decode over dense slot caches — the same
     streaming body, indexed contiguously (no table).  Returns (o, m, l).
@@ -250,5 +253,5 @@ def dense_attention_kernel(
         out_specs=out_specs,
     )
     return pl.pallas_call(
-        body, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
+        body, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret_mode(interpret),
     )(kv_len, q, k, v)

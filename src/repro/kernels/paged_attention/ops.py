@@ -21,6 +21,7 @@ output is returned in the query dtype.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +46,7 @@ def paged_attention_decode(
     v_scale: jax.Array = None,  # [KVH] f32
     *,
     softcap: float = 0.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     b, h, hd = q.shape
     kvh = k_pool.shape[1]
@@ -73,7 +74,7 @@ def paged_attention_prefill(
     v_scale: jax.Array = None,  # [KVH] f32
     *,
     softcap: float = 0.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Causal suffix attention with pooled past: query ``(b, i)`` sits at
     absolute position ``start[b] + i`` and sees every earlier pooled
@@ -103,7 +104,7 @@ def dense_attention_decode(
     *,
     softcap: float = 0.0,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     b, h, hd = q.shape
     kvh = k.shape[1]

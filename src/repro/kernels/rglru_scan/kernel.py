@@ -12,10 +12,13 @@ VMEM: two [bb, chunk, D] blocks; with bb=8, chunk=256, D=512 fp32 that is
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 
 def _assoc(e1, e2):
@@ -49,7 +52,7 @@ def rglru_scan_kernel(
     *,
     bb: int = 8,
     chunk: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     bsz, s, d = a.shape
     assert bsz % bb == 0 and s % chunk == 0, (bsz, s, bb, chunk)
@@ -68,6 +71,6 @@ def rglru_scan_kernel(
             jax.ShapeDtypeStruct((bsz, s, d), a.dtype),
             jax.ShapeDtypeStruct((bsz, d), a.dtype),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(a, b)
     return o

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +11,8 @@ from repro.kernels.rglru_scan.kernel import rglru_scan_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "chunk", "interpret"))
-def rglru_scan(a: jax.Array, b: jax.Array, *, bb: int = 8, chunk: int = 256, interpret: bool = True):
+def rglru_scan(a: jax.Array, b: jax.Array, *, bb: int = 8, chunk: int = 256,
+               interpret: Optional[bool] = None):
     bsz, s, d = a.shape
     bb = min(bb, bsz)
     chunk = min(chunk, s)

@@ -20,10 +20,13 @@ Layout: streams are pre-transposed so both operands are K-contiguous:
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 
 def _kernel(xs_ref, sx_ref, ws_ref, sw_ref, o_ref):
@@ -56,7 +59,7 @@ def stoch_matmul_packed_kernel(
     bm: int = 32,
     bn: int = 32,
     bk: int = 32,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     m, k, w = xs.shape
     n = ws.shape[0]
@@ -73,5 +76,5 @@ def stoch_matmul_packed_kernel(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xs, sx, ws, sw)

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +21,7 @@ def _pad(a: jax.Array, mult: int, axis: int) -> jax.Array:
     return jnp.pad(a, widths)
 
 
-def stoch_matmul_packed(xs, sx, ws, sw, *, bm=32, bn=32, bk=32, interpret=True):
+def stoch_matmul_packed(xs, sx, ws, sw, *, bm=32, bn=32, bk=32, interpret=None):
     """Packed-stream matmul with automatic block padding."""
     m, k = sx.shape
     n = sw.shape[0]
@@ -37,7 +38,7 @@ def stoch_matmul(
     wq: QTensor,
     x_gen: str = "thermometer",
     w_gen: str = "bresenham",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Quantized [M,K] @ [K,N] through the OSSM-array kernel, dequantized."""
     xs, sx, ws, sw = encode_operands(xq.q, wq.q, x_gen, w_gen)

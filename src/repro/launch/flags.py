@@ -117,8 +117,9 @@ def add_serve_flags(ap: argparse.ArgumentParser) -> None:
                          "§Decode-attention memory model): naive = jnp "
                          "einsum; flash = Pallas kernels (gather-free "
                          "streaming decode over the paged pool, flash "
-                         "prefill; interpret mode on CPU — correct but "
-                         "slow off-TPU)")
+                         "prefill; interpret mode chosen by backend: "
+                         "compiled on TPU, interpreted — correct but "
+                         "slow — elsewhere)")
     ap.add_argument("--max-queue", type=int, default=-1,
                     help="admission queue capacity (0 = no waiting room, "
                          "-1 = unbounded); overflow is rejected as "

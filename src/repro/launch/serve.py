@@ -17,6 +17,10 @@ registry — docs/PLANS.md); ``--mode`` remains as the uniform shorthand.
 ``--calibrate`` runs a PTQ calibration pass (per-site activation scales)
 on a synthetic batch before serving.
 
+Weights are held as ``Model.serving_params`` stores them: GEMM weights
+and embedding tables in the model's compute dtype (bf16), which halves
+them against the f32 init and leaves exact mode's outputs unchanged.
+
 KV memory is paged by default (``--kv-block-size``, docs/SERVING.md):
 attention KV lives in fixed-size pooled blocks with radix-tree prefix
 reuse on pure global-attention stacks (``--no-prefix-cache`` disables the
@@ -67,6 +71,7 @@ from repro.configs import get_arch
 from repro.core.astra_layer import MODES
 from repro.core.energy import AstraChipConfig
 from repro.core.plan import PRESET_PLANS, ExecutionPlan
+from repro.launch.compile_cache import place_compile_cache
 from repro.launch.flags import add_serve_flags, validate_serve_flags
 from repro.models.model import Model
 from repro.models.transformer import ModelOptions
@@ -123,7 +128,8 @@ def _prompt_lengths(args) -> list:
     return [args.prompt_len] * args.batch
 
 
-def _make_prompts(cfg, lengths, key):
+def make_prompts(cfg, lengths, key):
+    """Random in-vocab prompts of the given lengths, one fold of ``key`` each."""
     prompts = []
     for i, l in enumerate(lengths):
         k = jax.random.fold_in(key, i)
@@ -132,7 +138,9 @@ def _make_prompts(cfg, lengths, key):
     return prompts
 
 
-def _run_engine(model, params, prompts, args, sampler):
+def run_engine(model, params, prompts, args, sampler):
+    """Serve ``prompts`` as the CLI does: a warm-up engine, then a timed
+    one.  Returns (outputs, timed tok/s, the timed engine)."""
     max_len = max(p.shape[-1] for p in prompts) + args.gen + 1
     cfg = ServeConfig(max_slots=args.max_slots or len(prompts), max_len=max_len,
                       chunk_steps=args.chunk_steps, sampler=sampler, seed=args.seed,
@@ -308,6 +316,7 @@ def main(argv=None):
                          "goodput line")
     args = ap.parse_args(argv)
     validate_serve_flags(ap, args)
+    place_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -316,9 +325,9 @@ def main(argv=None):
     sampler = SamplerConfig(args.temperature, args.top_k)
 
     base_model = Model(cfg, ModelOptions())
-    params = base_model.init(key)
+    params = base_model.serving_params(base_model.init(key))
     lengths = _prompt_lengths(args)
-    prompts = _make_prompts(cfg, lengths, key)
+    prompts = make_prompts(cfg, lengths, key)
 
     plan = _parse_plan(ap, args.plan) if args.plan else ExecutionPlan.from_spec(args.mode)
     plan_label = plan.name or args.plan or args.mode
@@ -342,7 +351,7 @@ def main(argv=None):
     if args.traffic_trace:
         trace = _load_trace(ap, args.traffic_trace, cfg)
         return _run_traffic(model, params, trace, args, sampler)
-    outs, tps, engine = _run_engine(model, params, prompts, args, sampler)
+    outs, tps, engine = run_engine(model, params, prompts, args, sampler)
     print(f"[{plan_label}] {len(outs)} requests (prompt lens {sorted(set(lengths))}), "
           f"{args.gen} new tokens each: {tps:.1f} tok/s")
     kv = engine.kv_stats
@@ -391,7 +400,7 @@ def main(argv=None):
 
     all_exact = all(model.plan.resolve(s).mode == "exact" for s in model_sites(cfg))
     if args.compare_exact and not all_exact:
-        outs_ref, _, _eng = _run_engine(base_model, params, prompts, args, sampler)
+        outs_ref, _, _eng = run_engine(base_model, params, prompts, args, sampler)
         agree = np.mean([
             np.mean(o.tokens == r.tokens) for o, r in zip(outs, outs_ref)
         ])

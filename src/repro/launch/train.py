@@ -1,8 +1,9 @@
 """End-to-end training driver: data -> model -> AdamW -> checkpoint/restart.
 
-The CPU-runnable face of the same stack the dry-run lowers for 512 chips:
+The runnable face of the same stack the dry-run lowers for 512 chips:
 identical step function, sharding rules, and checkpoint format — only the
-mesh differs (host mesh here, ``make_production_mesh`` on the pod).
+mesh differs (a host mesh over every local device here,
+``make_production_mesh`` on the pod).
 
 Fault tolerance is on by default: atomic async checkpoints every
 ``--ckpt-every`` steps, automatic resume from the newest checkpoint, and an
@@ -24,6 +25,7 @@ import jax
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_arch
 from repro.data import DataConfig, Prefetcher, SyntheticLMDataset
+from repro.launch.compile_cache import place_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.model import Model
 from repro.models.transformer import ModelOptions
@@ -45,7 +47,9 @@ def build_train_step(model: Model, ocfg: AdamWConfig, total_steps: int, warmup: 
     return train_step
 
 
-def main(argv=None):
+def main(argv=None, mesh=None):
+    """Train per ``argv``.  ``mesh`` (optional) replaces the mesh the flags
+    would build — e.g. a one-device mesh to compare a sharded run against."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--reduced", action="store_true", help="tiny same-family config (CPU)")
@@ -64,11 +68,13 @@ def main(argv=None):
                     help="background data prefetch w/ straggler deadline+backup")
     ap.add_argument("--prefetch-timeout", type=float, default=30.0)
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    mesh = make_production_mesh() if args.production_mesh else make_host_mesh()
+    if mesh is None:
+        mesh = make_production_mesh() if args.production_mesh else make_host_mesh()
     model = Model(cfg, ModelOptions())
     ocfg = AdamWConfig(lr=args.lr)
 

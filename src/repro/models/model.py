@@ -9,6 +9,7 @@ Llama-Vision gets precomputed patch embeddings.
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -32,6 +33,14 @@ def cross_entropy(logits: jax.Array, labels: jax.Array, z_loss: float = 0.0):
     if z_loss:
         loss = loss + z_loss * ((logz**2) * valid).sum() / jnp.maximum(valid.sum(), 1.0)
     return loss
+
+
+# Leaves every forward casts to the activations' dtype before use: GEMM
+# weights, MoE expert weights and embedding tables.  Left as they are: the
+# f32 GEMMs (MoE router; RG-LRU gates, which read the f32 conv output),
+# norms, biases and recurrence parameters.
+_SERVE_CAST = re.compile(
+    r"(?<!router)(?<!w_a)(?<!w_x)/w$|mlp/w_(up|gate|down)$|embedding/table$")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,6 +70,21 @@ class Model:
     # ------------------------------------------------------------- params
     def init(self, key) -> Dict[str, Any]:
         return init_params(key, self.cfg)
+
+    def serving_params(self, params) -> Dict[str, Any]:
+        """``params`` as a server holds them: the leaves each forward casts
+        to the compute dtype anyway (``_SERVE_CAST``) stored in it.  Exact
+        mode's outputs are unchanged and a bf16 model's resident weights
+        halve; quantized modes quantize from the stored values."""
+        dt = jnp.bfloat16 if self.cfg.dtype == "bfloat16" else jnp.float32
+
+        def one(path, a):
+            name = jax.tree_util.keystr(path, simple=True, separator="/")
+            # multi-codebook embeddings are summed in f32 before the cast
+            keep = self.cfg.n_codebooks and name.endswith("table")
+            return a if keep or not _SERVE_CAST.search(name) else a.astype(dt)
+
+        return jax.tree_util.tree_map_with_path(one, params)
 
     def param_shapes(self) -> Dict[str, Any]:
         return jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0)))

@@ -46,7 +46,7 @@ class ModelOptions:
     plan: Optional[Union[ExecutionPlan, str, dict, ComputeConfig]] = None
     cc: Optional[ComputeConfig] = None  # DEPRECATED -> uniform plan
     # naive = jnp einsum everywhere; flash = Pallas attention kernels
-    # (interpret on CPU): flash_attention on the sequence path, the
+    # (interpret mode chosen by backend): flash_attention on the sequence path, the
     # gather-free paged_attention kernels on decode and paged suffix
     # prefill.  Kernels cover exact qk/pv only — quantized dynamic sites
     # fall back to the astra-batched path per site.

@@ -907,6 +907,17 @@ class ServeEngine:
             self._tables_dirty = False
         return BlockTables(self._tables_dev, jnp.int32(self._ring_len))
 
+    def lower_decode_chunk(self, steps: Optional[int] = None):
+        """The fused decode chunk as ``step`` dispatches it (no fault
+        gates), lowered against the engine's current state; ``steps``
+        defaults to ``chunk_steps``.  ``.compile().as_text()`` is the
+        program the device runs."""
+        pos = jnp.zeros(self.config.max_slots, jnp.int32)
+        return self._fused.lower(
+            self.params, self._cur_tok, self._states, pos, self._key,
+            steps=steps or self.config.chunk_steps, sampler=self.config.sampler,
+            tables=self._block_tables() if self._paged else None)
+
     # -------------------------------------------------- fault containment
     def quarantine_slot(self, slot_i: int, reason: str,
                         scrub: bool = True) -> None:

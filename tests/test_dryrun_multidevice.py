@@ -23,13 +23,14 @@ SCRIPT = textwrap.dedent(
     import jax, jax.numpy as jnp
 
     from repro.configs import get_arch
+    from repro.launch.mesh import make_mesh
     from repro.launch.dryrun import parse_collectives
     from repro.models.model import Model, input_specs
     from repro.models.transformer import ModelOptions
     from repro.optim import AdamWConfig, adamw_init, adamw_update
     from repro.parallel.sharding import activation_mesh, batch_specs, param_specs
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_arch("qwen3-moe-30b-a3b").reduced()  # MoE: exercises EP + FSDP + TP
     model = Model(cfg, ModelOptions())
     param_shapes = model.param_shapes()

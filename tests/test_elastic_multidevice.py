@@ -25,6 +25,7 @@ SCRIPT = textwrap.dedent(
 
     from repro.checkpoint import CheckpointManager
     from repro.configs import get_arch
+    from repro.launch.mesh import make_mesh
     from repro.data import DataConfig, SyntheticLMDataset
     from repro.launch.train import build_train_step
     from repro.models.model import Model
@@ -63,7 +64,7 @@ SCRIPT = textwrap.dedent(
         return params, opt, losses
 
     # --- uninterrupted 8-device reference run (5 steps) ---
-    mesh8 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh8 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     p_sh8 = param_specs(model.param_shapes(), mesh8)
     with mesh8, activation_mesh(mesh8):
         params0 = jax.jit(model.init, out_shardings=p_sh8)(jax.random.PRNGKey(0))
@@ -79,7 +80,7 @@ SCRIPT = textwrap.dedent(
     mgr.save(2, {"params": params, "opt": opt})
 
     # "pod loss": rebuild on the first 4 devices only
-    mesh4 = jax.make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+    mesh4 = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
     template = {"params": model.param_shapes(),
                 "opt": jax.eval_shape(adamw_init, model.param_shapes())}
     shardings = {"params": param_specs(template["params"], mesh4),

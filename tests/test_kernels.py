@@ -1,13 +1,17 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, bit-exactness.
 
-Every kernel runs in interpret mode (CPU) and must match its ref.py oracle
-exactly (integer kernels) or to fp tolerance (flash attention).
+Interpret mode is chosen by backend, so on the CPU every kernel runs
+interpreted and must match its ref.py oracle exactly (integer kernels)
+or to fp tolerance (flash attention).  test_tpu_compile.py compiles the
+serving kernels for the TPU.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.quant import quantize
+from repro.kernels import interpret_mode
 from repro.kernels.bts_encode.ops import bts_encode
 from repro.kernels.bts_encode.ref import bts_encode_ref
 from repro.kernels.flash_attention.ops import flash_attention
@@ -26,6 +30,12 @@ from repro.kernels.stoch_matmul.ops import stoch_matmul, stoch_matmul_packed
 from repro.kernels.stoch_matmul.ref import (
     encode_operands, stoch_matmul_packed_ref, stoch_matmul_ref,
 )
+
+
+def test_interpret_mode_chosen_by_backend():
+    assert interpret_mode() is (jax.default_backend() != "tpu")
+    assert interpret_mode(True) is True
+    assert interpret_mode(False) is False
 
 
 # ------------------------------------------------------------- stoch_matmul

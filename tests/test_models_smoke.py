@@ -119,3 +119,24 @@ def test_param_counts_in_band():
     # MoE actives
     assert get_arch("qwen3-moe-30b-a3b").active_param_count() < 5e9
     assert get_arch("granite-moe-1b-a400m").active_param_count() < 0.6e9
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_serving_params_keep_exact_logits(arch, key):
+    """Serving params store the GEMM/expert weights and embedding tables in
+    the compute dtype; every use casts them to it anyway, so exact-mode
+    logits are bit-identical to the f32 params'."""
+    cfg = get_arch(arch).reduced()
+    model = Model(cfg, ModelOptions())
+    params = model.init(key)
+    served = model.serving_params(params)
+    leaves = jax.tree_util.tree_flatten_with_path(served)[0]
+    narrowed = [jax.tree_util.keystr(p) for p, a in leaves if a.dtype == jnp.bfloat16]
+    assert sum(a.size for _, a in leaves if a.dtype == jnp.bfloat16) > 0.5 * sum(
+        a.size for _, a in leaves)
+    assert not any("router" in n or "norm" in n for n in narrowed), narrowed
+    batch = _batch_for(cfg, 2, 16, key)
+    ref, _ = model.prefill(params, batch)
+    got, _ = model.prefill(served, batch)
+    assert got.dtype == ref.dtype
+    assert bool(jnp.array_equal(got, ref)), float(jnp.max(jnp.abs(got - ref)))

@@ -204,6 +204,26 @@ def test_paged_kernel_matches_sdpa_engine(arch, key):
         np.testing.assert_array_equal(a.tokens, b.tokens)
 
 
+def test_lower_decode_chunk_is_the_dispatched_program(key):
+    """``lower_decode_chunk`` lowers the chunk ``step`` dispatches: after a
+    run it compiles against the engine's state, emits [slots, steps]
+    tokens, and follows the engine's attention path."""
+    model = _model("stablelm-1.6b")
+    params = model.init(key)
+    prompts = _prompts(model.cfg, (6, 11, 16))
+    texts = {}
+    for impl in ("naive", "flash"):
+        eng = ServeEngine(model, params,
+                          ServeConfig(max_slots=3, max_len=26, chunk_steps=4,
+                                      kv_block_size=8, attn_impl=impl))
+        eng.generate_batch(prompts, 8)
+        lowered = eng.lower_decode_chunk()
+        assert lowered.out_info[0].shape == (3, 4)
+        assert eng.lower_decode_chunk(3).out_info[0].shape == (3, 3)
+        texts[impl] = lowered.compile().as_text()
+    assert texts["naive"] != texts["flash"]
+
+
 def test_dense_kernel_matches_sdpa_engine(key):
     """Dense layout: the length-masked decode kernel (and the flash
     full-sequence prefill) must be invisible to outputs too."""

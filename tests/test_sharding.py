@@ -2,6 +2,7 @@
 tests run on 1 CPU device; AbstractMesh carries only the axis geometry).
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import AbstractMesh
@@ -116,3 +117,52 @@ def test_moe_expert_dim_sharded():
 
 def _path(path):
     return "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
+
+
+def _axes_named(spec):
+    return [a for e in spec if e is not None for a in (e if isinstance(e, tuple) else (e,))]
+
+
+def test_guard_names_each_mesh_axis_once():
+    """No decode-state rule names a mesh axis in two dims (JAX rejects
+    such a spec), in either KV layout."""
+    for arch in ("qwen2.5-32b", "recurrentgemma-2b", "xlstm-125m"):
+        cfg = get_arch(arch)
+        states = input_specs(cfg, SHAPES["decode_32k"])["states"]
+        for layout in ("heads", "seq"):
+            for _, sh in _flat(state_specs(states, MESH3, 128, kv_layout=layout)):
+                axes = _axes_named(_spec_of(sh))
+                assert len(axes) == len(set(axes)), (arch, layout, _spec_of(sh))
+
+
+@pytest.mark.parametrize("strategy", ["tp_fsdp", "fsdp", "ep_dp", "tp"])
+def test_param_specs_never_repeat_an_axis(strategy):
+    for arch in ("qwen1.5-0.5b", "qwen3-moe-30b-a3b", "recurrentgemma-2b"):
+        shapes = Model(get_arch(arch), ModelOptions()).param_shapes()
+        for _, sh in _flat(param_specs(shapes, MESH3, strategy)):
+            axes = _axes_named(_spec_of(sh))
+            assert len(axes) == len(set(axes)), (arch, _spec_of(sh))
+
+
+# the logical layouts the model code passes to shard_act
+_ACT_LAYOUTS = (
+    ("batch", None, None),
+    ("batch", None, "ffn"),
+    ("batch", None, "rnn"),
+    ("batch", "heads", None, None),
+    ("batch", "experts", None, None),
+)
+
+
+@pytest.mark.parametrize("strategy", ["tp_fsdp", "fsdp", "ep_dp", "tp"])
+def test_shard_act_names_each_mesh_axis_once(monkeypatch, strategy):
+    """Every activation layout in use names each mesh axis at most once."""
+    from repro.parallel import sharding
+
+    monkeypatch.setattr(sharding.jax.lax, "with_sharding_constraint", lambda x, s: s)
+    for logical in _ACT_LAYOUTS:
+        x = jax.ShapeDtypeStruct((512, 64, 256, 128)[:len(logical)], jnp.float32)
+        with sharding.activation_mesh(MESH3, strategy):
+            spec = tuple(sharding.shard_act(x, logical).spec)
+        axes = _axes_named(spec)
+        assert axes and len(axes) == len(set(axes)), (logical, spec)
