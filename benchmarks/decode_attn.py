@@ -13,9 +13,8 @@ Claim under test (ISSUE 5): **>=2x lower decode-attention step cost at
 >=8 resident blocks per slot, token-identical outputs.**
 
 The step-cost claim is scored on modeled per-step KV HBM traffic at the
-deployment target (the ASTRA/TPU roofline convention of
-``benchmarks/roofline.py`` — decode attention is bandwidth-bound, so
-bytes moved is the step cost):
+deployment target (decode attention is bandwidth-bound, so bytes
+moved is the step cost):
 
 * baseline — the gather reads the full table extent from the pool,
   writes the logical copy, and ``_sdpa`` reads it back:

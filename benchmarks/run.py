@@ -1,4 +1,4 @@
-"""Benchmark aggregator: one section per paper table/figure + the roofline.
+"""Benchmark aggregator: one section per paper table/figure.
 
   PYTHONPATH=src python -m benchmarks.run [--only accuracy,speedup,...]
                                           [--tune-env]
@@ -28,7 +28,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 from benchmarks import (
     accuracy, decode_attn, energy_breakdown, energy_comparison, faults,
-    kv_quant, pairing_ablation, roofline, serve_throughput, speedup, traffic,
+    kv_quant, pairing_ablation, serve_throughput, speedup, traffic,
     vdpe_scaling,
 )
 
@@ -39,8 +39,6 @@ SECTIONS = {
     "speedup": speedup.run,                 # SIII speedup claim
     "pairing_ablation": pairing_ablation.run,  # beyond-paper: decorrelation study
     "accuracy": accuracy.run,               # SIII accuracy claim (trains a model)
-    "roofline": roofline.run,               # assignment SRoofline
-    "roofline_compare": roofline.compare,   # SPerf: baseline vs optimized bounds
     "serve_throughput": serve_throughput.run,  # ISSUE 1: fused vs per-step decode
     "kv_cache": serve_throughput.run_kv_cache,  # ISSUE 3: shared-prefix TTFT
     "scheduler": serve_throughput.run_scheduler,  # ISSUE 4: chunked-prefill ITL

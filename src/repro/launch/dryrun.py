@@ -16,8 +16,7 @@ placeholder devices stand in for 2 TPU v5e pods.  For each cell we
   4. record ``memory_analysis()`` (fits-per-device evidence),
      ``cost_analysis()`` (FLOPs / bytes for the roofline), and the
      collective schedule parsed from the optimized HLO,
-  5. dump one JSON artifact per cell under --out (consumed by
-     ``benchmarks/roofline.py`` and EXPERIMENTS.md).
+  5. dump one JSON artifact per cell under --out.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2.5-32b --shape train_4k

@@ -59,6 +59,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.energy import AstraChipConfig
 from repro.core.plan import validate_site_registry
@@ -83,6 +84,7 @@ from repro.serve.scheduler import (
     DegradedLadder, SchedulerConfig, TokenBudgetScheduler, pow2_bucket,
 )
 from repro.serve.slots import SlotState, paged_scatter_states, scatter_states
+from repro.serve import tracing
 
 _paged_scatter = jax.jit(paged_scatter_states)
 
@@ -512,11 +514,12 @@ class ServeEngine:
         fault-free replay; without a supervisor the fault propagates to
         the caller (loud by design)."""
         self._step_no += 1
-        self._admit()
-        if self._sched is not None:
-            self._prefill_chunk()
-        self._decode_chunk(faults)
-        self._check_progress()
+        with StepTraceAnnotation(tracing.STEP, step_num=self._step_no):
+            self._admit()
+            if self._sched is not None:
+                self._prefill_chunk()
+            self._decode_chunk(faults)
+            self._check_progress()
         return self._drain()
 
     def _drain(self) -> List[RequestOutput]:
@@ -576,10 +579,11 @@ class ServeEngine:
         if n == 0:
             return
         before = len(self._queue)
-        if self._sched is not None:
-            self._admit_chunked(free[:n])
-        else:
-            self._admit_blocking(free[:n])
+        with TraceAnnotation(tracing.ADMIT):
+            if self._sched is not None:
+                self._admit_chunked(free[:n])
+            else:
+                self._admit_blocking(free[:n])
         if len(self._queue) < before:
             self._admit_progress = True
 
@@ -637,24 +641,27 @@ class ServeEngine:
         else:
             last_logits = self._prefill_dense(slot_ids, reqs)
             cached = [0] * len(reqs)
-        self._key, sub = jax.random.split(self._key)
-        first = sample_next_token(last_logits, self.config.sampler, sub, self.model.cfg)
-        ids = jnp.asarray(slot_ids, jnp.int32)
-        self._cur_tok = self._cur_tok.at[ids].set(first)
-        first_np = np.asarray(first)  # [n, 1] or [n, C, 1]
-        t_first = self.clock()
-        for j, (i, req) in enumerate(zip(slot_ids, reqs)):
-            tok0 = first_np[j]  # [1] or [C, 1]
-            slot = _Slot(req, SlotState.DECODING, pos=req.prompt_len,
-                         remaining=req.max_new_tokens - 1, filled=req.prompt_len,
-                         generated=[tok0], cached=cached[j], t_admit=t_admit,
-                         t_first=t_first, events=[(t_first, 1)])
-            self._emit_tokens(req, tok0)
-            if self._hit_eos(req, tok0) or slot.remaining == 0:
-                self._retire(slot)
-                self._release_blocks(i)
-            else:
-                self._slots[i] = slot
+        with TraceAnnotation(tracing.FIRST_TOKEN):
+            self._key, sub = jax.random.split(self._key)
+            first = sample_next_token(last_logits, self.config.sampler, sub,
+                                      self.model.cfg)
+            ids = jnp.asarray(slot_ids, jnp.int32)
+            self._cur_tok = self._cur_tok.at[ids].set(first)
+            with TraceAnnotation(tracing.HOST_SYNC):
+                first_np = np.asarray(first)  # [n, 1] or [n, C, 1]
+            t_first = self.clock()
+            for j, (i, req) in enumerate(zip(slot_ids, reqs)):
+                tok0 = first_np[j]  # [1] or [C, 1]
+                slot = _Slot(req, SlotState.DECODING, pos=req.prompt_len,
+                             remaining=req.max_new_tokens - 1, filled=req.prompt_len,
+                             generated=[tok0], cached=cached[j], t_admit=t_admit,
+                             t_first=t_first, events=[(t_first, 1)])
+                self._emit_tokens(req, tok0)
+                if self._hit_eos(req, tok0) or slot.remaining == 0:
+                    self._retire(slot)
+                    self._release_blocks(i)
+                else:
+                    self._slots[i] = slot
 
     def _packed_prefill_small(self, reqs: List[Request]):
         """Cold prefill of ``reqs`` at batch len(reqs) with dense states."""
@@ -777,27 +784,36 @@ class ServeEngine:
         for this round, then DECODING transitions for completed prompts."""
         if not self._prefilling:
             return
-        n_active = sum(1 for s in self._slots
-                       if s is not None and s.state is SlotState.DECODING)
-        needs = [(i, self._slots[i].req.prompt_len - self._slots[i].filled)
-                 for i in self._prefilling]
-        plan = self._sched.plan_chunks(needs, n_active)
-        if not plan:
-            return
-        if self._paged:
-            last_logits = self._prefill_chunk_paged(plan)  # [n_sel, 1, ...]
-            row_of = {i: j for j, (i, _) in enumerate(plan)}
-        else:
-            last_logits = self._prefill_chunk_dense(plan)  # [B, 1, ...]
-            row_of = {i: i for i, _ in plan}
-        done: List[int] = []
-        for i, take in plan:
-            slot = self._slots[i]
-            slot.filled += take
-            if slot.filled == slot.req.prompt_len:
-                done.append(i)
-        if done:
-            self._start_decoding(done, last_logits, [row_of[i] for i in done])
+        with TraceAnnotation(tracing.PREFILL_CHUNK) as span:
+            n_active = sum(1 for s in self._slots
+                           if s is not None and s.state is SlotState.DECODING)
+            needs = [(i, self._slots[i].req.prompt_len - self._slots[i].filled)
+                     for i in self._prefilling]
+            plan = self._sched.plan_chunks(needs, n_active)
+            if not plan:
+                return
+            # the dispatched grid and its real tokens (docs/SERVING.md §Tracing)
+            span.set_metadata(rows=len(plan) if self._paged else self.config.max_slots,
+                              tokens=sum(t for _, t in plan),
+                              width=self._chunk_width(plan))
+            if self._paged:
+                last_logits = self._prefill_chunk_paged(plan)  # [n_sel, 1, ...]
+                row_of = {i: j for j, (i, _) in enumerate(plan)}
+            else:
+                last_logits = self._prefill_chunk_dense(plan)  # [B, 1, ...]
+                row_of = {i: i for i, _ in plan}
+            done: List[int] = []
+            for i, take in plan:
+                slot = self._slots[i]
+                slot.filled += take
+                if slot.filled == slot.req.prompt_len:
+                    done.append(i)
+            if done:
+                self._start_decoding(done, last_logits, [row_of[i] for i in done])
+
+    def _chunk_width(self, plan: List[Tuple[int, int]]) -> int:
+        """Pow2 token width of a prefill chunk's grid."""
+        return pow2_bucket(max(t for _, t in plan), self.config.prefill_chunk_tokens)
 
     def _chunk_tokens(self, plan: List[Tuple[int, int]], width: int,
                       rows: Optional[List[int]] = None) -> np.ndarray:
@@ -818,8 +834,7 @@ class ServeEngine:
         """Chunked suffix prefill against the paged pool: each selected
         slot's resident prefix is its prefix-cache hit plus its own earlier
         chunks (``starts`` need not be block-aligned)."""
-        width = pow2_bucket(max(t for _, t in plan),
-                            self.config.prefill_chunk_tokens)
+        width = self._chunk_width(plan)
         tokens = jnp.asarray(self._chunk_tokens(plan, width))
         starts = [self._slots[i].filled for i, _ in plan]
         lengths = jnp.asarray([t for _, t in plan], jnp.int32)
@@ -827,10 +842,13 @@ class ServeEngine:
             self._real_row(i) for i, _ in plan
         ]))
         ctx = self._ctx_bucket(max(s + width for s in starts))
-        last_logits, self._states = prefill_paged_suffix(
-            self.model, self.params, tokens, lengths, self._states,
-            rows_dev, jnp.asarray(starts, jnp.int32), ctx,
-        )
+        # the dispatch waits while the program before it holds the device
+        # memory its outputs need (docs/SERVING.md §Tracing)
+        with TraceAnnotation(tracing.HOST_SYNC):
+            last_logits, self._states = prefill_paged_suffix(
+                self.model, self.params, tokens, lengths, self._states,
+                rows_dev, jnp.asarray(starts, jnp.int32), ctx,
+            )
         return last_logits
 
     def _real_row(self, slot_i: int) -> np.ndarray:
@@ -842,8 +860,7 @@ class ServeEngine:
     def _prefill_chunk_dense(self, plan: List[Tuple[int, int]]):
         """Chunked dense prefill: one windowed masked scan over the full
         engine state — selected slots advance, everything else is gated."""
-        width = pow2_bucket(max(t for _, t in plan),
-                            self.config.prefill_chunk_tokens)
+        width = self._chunk_width(plan)
         b = self.config.max_slots
         tokens = jnp.asarray(
             self._chunk_tokens(plan, width, rows=[i for i, _ in plan]))
@@ -852,42 +869,45 @@ class ServeEngine:
         for i, take in plan:
             starts[i] = self._slots[i].filled
             lengths[i] = take
-        last_logits, self._states = prefill_window(
-            self.model, self.params, tokens, jnp.asarray(starts),
-            jnp.asarray(lengths), self._states,
-        )
+        with TraceAnnotation(tracing.HOST_SYNC):
+            last_logits, self._states = prefill_window(
+                self.model, self.params, tokens, jnp.asarray(starts),
+                jnp.asarray(lengths), self._states,
+            )
         return last_logits
 
     def _start_decoding(self, slot_ids: List[int], last_logits, rows: List[int]):
         """PREFILLING -> DECODING: sample each completed prompt's first
         token, expose paged table rows, intern prefix blocks."""
-        self._key, sub = jax.random.split(self._key)
-        logits = last_logits[jnp.asarray(rows, jnp.int32)]
-        first = sample_next_token(logits, self.config.sampler, sub, self.model.cfg)
-        ids = jnp.asarray(slot_ids, jnp.int32)
-        self._cur_tok = self._cur_tok.at[ids].set(first)
-        first_np = np.asarray(first)
-        t_first = self.clock()
-        for j, i in enumerate(slot_ids):
-            slot = self._slots[i]
-            req = slot.req
-            tok0 = first_np[j]
-            slot.state = SlotState.DECODING
-            slot.pos = req.prompt_len
-            slot.remaining = req.max_new_tokens - 1
-            slot.generated = [tok0]
-            slot.t_first = t_first
-            slot.events = [(t_first, 1)]
-            self._emit_tokens(req, tok0)
-            self._prefilling.remove(i)
-            if self._paged:
-                self._install_blocks(i, self._slot_blocks[i], into_table=True)
-                if self._prefix is not None:
-                    self._intern_prompt(i, req, slot.cached)
-            if self._hit_eos(req, tok0) or slot.remaining == 0:
-                self._retire(slot)
-                self._release_blocks(i)
-                self._slots[i] = None
+        with TraceAnnotation(tracing.FIRST_TOKEN):
+            self._key, sub = jax.random.split(self._key)
+            logits = last_logits[jnp.asarray(rows, jnp.int32)]
+            first = sample_next_token(logits, self.config.sampler, sub, self.model.cfg)
+            ids = jnp.asarray(slot_ids, jnp.int32)
+            self._cur_tok = self._cur_tok.at[ids].set(first)
+            with TraceAnnotation(tracing.HOST_SYNC):
+                first_np = np.asarray(first)
+            t_first = self.clock()
+            for j, i in enumerate(slot_ids):
+                slot = self._slots[i]
+                req = slot.req
+                tok0 = first_np[j]
+                slot.state = SlotState.DECODING
+                slot.pos = req.prompt_len
+                slot.remaining = req.max_new_tokens - 1
+                slot.generated = [tok0]
+                slot.t_first = t_first
+                slot.events = [(t_first, 1)]
+                self._emit_tokens(req, tok0)
+                self._prefilling.remove(i)
+                if self._paged:
+                    self._install_blocks(i, self._slot_blocks[i], into_table=True)
+                    if self._prefix is not None:
+                        self._intern_prompt(i, req, slot.cached)
+                if self._hit_eos(req, tok0) or slot.remaining == 0:
+                    self._retire(slot)
+                    self._release_blocks(i)
+                    self._slots[i] = None
 
     # ------------------------------------------------------ paged helpers
     def _release_blocks(self, slot_i: int):
@@ -1126,46 +1146,52 @@ class ServeEngine:
             poison = jnp.asarray(p)
         steps = min(self.config.chunk_steps,
                     min(self._slots[i].remaining for i in active))
-        pos = np.zeros(self.config.max_slots, np.int32)
-        for i in active:
-            pos[i] = self._slots[i].pos
-        mask = None
-        if (self._sched is not None and not self._paged
-                and len(active) < sum(s is not None for s in self._slots)):
-            # dense + PREFILLING slots present: gate ride-along state
-            # updates so half-prefilled recurrent/KV state stays intact
-            m = np.zeros(self.config.max_slots, bool)
-            m[active] = True
-            mask = jnp.asarray(m)
-        self._key, sub = jax.random.split(self._key)
-        toks, finite, (next_tok, states, _, _) = self._fused(
-            self.params, self._cur_tok, self._states, jnp.asarray(pos), sub,
-            steps=steps, sampler=self.config.sampler,
-            tables=self._block_tables() if self._paged else None,
-            active=mask, poison=poison,
-        )
+        with TraceAnnotation(tracing.DECODE_DISPATCH, steps=steps):
+            pos = np.zeros(self.config.max_slots, np.int32)
+            for i in active:
+                pos[i] = self._slots[i].pos
+            mask = None
+            if (self._sched is not None and not self._paged
+                    and len(active) < sum(s is not None for s in self._slots)):
+                # dense + PREFILLING slots present: gate ride-along state
+                # updates so half-prefilled recurrent/KV state stays intact
+                m = np.zeros(self.config.max_slots, bool)
+                m[active] = True
+                mask = jnp.asarray(m)
+            self._key, sub = jax.random.split(self._key)
+            # the dispatch waits while a prefill program holds the device
+            # memory its outputs need (docs/SERVING.md §Tracing)
+            with TraceAnnotation(tracing.HOST_SYNC):
+                toks, finite, (next_tok, states, _, _) = self._fused(
+                    self.params, self._cur_tok, self._states, jnp.asarray(pos), sub,
+                    steps=steps, sampler=self.config.sampler,
+                    tables=self._block_tables() if self._paged else None,
+                    active=mask, poison=poison,
+                )
         self._states = states
         self._cur_tok = next_tok
-        toks_np = np.asarray(toks)  # [B, steps] or [B, C, steps]
-        finite_np = np.asarray(finite)  # [B] bool, ANDed over the chunk
+        with TraceAnnotation(tracing.HOST_SYNC):
+            toks_np = np.asarray(toks)  # [B, steps] or [B, C, steps]
+            finite_np = np.asarray(finite)  # [B] bool, ANDed over the chunk
         bad = [i for i in active if not finite_np[i]]
-        t_now = self.clock()
-        for i in active:
-            if i in bad:
-                # the slot's tokens this chunk are garbage (sampled from
-                # non-finite logits): don't emit or account them — the
-                # request ends at its pre-fault stream via quarantine
-                continue
-            slot = self._slots[i]
-            slot.generated.append(toks_np[i])
-            slot.events.append((t_now, steps))
-            self._emit_tokens(slot.req, toks_np[i])
-            slot.pos += steps
-            slot.remaining -= steps
-            if slot.remaining == 0 or self._hit_eos(slot.req, toks_np[i]):
-                self._retire(slot)
-                self._release_blocks(i)
-                self._slots[i] = None
+        with TraceAnnotation(tracing.RETIRE):
+            t_now = self.clock()
+            for i in active:
+                if i in bad:
+                    # the slot's tokens this chunk are garbage (sampled from
+                    # non-finite logits): don't emit or account them — the
+                    # request ends at its pre-fault stream via quarantine
+                    continue
+                slot = self._slots[i]
+                slot.generated.append(toks_np[i])
+                slot.events.append((t_now, steps))
+                self._emit_tokens(slot.req, toks_np[i])
+                slot.pos += steps
+                slot.remaining -= steps
+                if slot.remaining == 0 or self._hit_eos(slot.req, toks_np[i]):
+                    self._retire(slot)
+                    self._release_blocks(i)
+                    self._slots[i] = None
         if bad:
             # healthy slots are fully committed above; the fault names
             # exactly the poisoned slots (injected or organic NaN alike)
@@ -1222,10 +1248,11 @@ class ServeEngine:
             gen = np.zeros(shape, np.int32)
         hw = None
         if self.config.astra_accounting:
-            hw = request_hardware_report(
-                self.model.cfg, self.chip, req.prompt_len, int(gen.shape[-1]),
-                cached_prompt_len=cached,
-            )
+            with TraceAnnotation(tracing.ACCOUNTING):
+                hw = request_hardware_report(
+                    self.model.cfg, self.chip, req.prompt_len, int(gen.shape[-1]),
+                    cached_prompt_len=cached,
+                )
         timing = request_timing(req.t_submit, t_admit, t_first, events, self.clock())
         self._outbox.append(RequestOutput(
             req.id, req.prompt, gen, timing.wall_time_s, hw, timing,

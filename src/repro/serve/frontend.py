@@ -49,10 +49,12 @@ from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve.accounting import RequestTiming
 from repro.serve.engine import RequestOutput, ServeEngine
 from repro.serve.faults import CANCEL_CLASS, CANCELLED, DEADLINE_EXCEEDED, RETRYABLE_FAULTS
+from repro.serve import tracing
 
 REJECT_QUEUE_FULL = "queue_full"
 REJECT_QUEUE_TIMEOUT = "queue_timeout"
@@ -265,11 +267,12 @@ class ServeFrontend:
         later ``pump()``.  Either way the terminal output arrives through
         ``drain()``/``run()``.
         """
-        prompt = self.engine.check_request(prompt, max_new_tokens)
-        rid = self.engine.allocate_request_id()
-        if on_tokens is not None:
-            self._callbacks[rid] = on_tokens
-        self._admit(rid, prompt, max_new_tokens, eos_id, deadline_s)
+        with TraceAnnotation(tracing.FRONTEND_SUBMIT):
+            prompt = self.engine.check_request(prompt, max_new_tokens)
+            rid = self.engine.allocate_request_id()
+            if on_tokens is not None:
+                self._callbacks[rid] = on_tokens
+            self._admit(rid, prompt, max_new_tokens, eos_id, deadline_s)
         return rid
 
     def stream(self, prompt, max_new_tokens: int,
@@ -322,14 +325,15 @@ class ServeFrontend:
         the engine up to ``max_concurrency``, run one engine round
         (through the supervisor when present), route finished outputs.
         Outputs accumulate for ``drain()``."""
-        now = self.clock()
-        self._expire(now)
-        self._check_deadlines(now)
-        now = self._revive_retries(now)
-        self._forward(now)
-        if self.engine.has_work() or self._inflight:
-            for out in self._stepper():
-                self._finish(out)
+        with TraceAnnotation(tracing.FRONTEND_PUMP):
+            now = self.clock()
+            self._expire(now)
+            self._check_deadlines(now)
+            now = self._revive_retries(now)
+            self._forward(now)
+            if self.engine.has_work() or self._inflight:
+                for out in self._stepper():
+                    self._finish(out)
 
     def drain(self) -> List[RequestOutput]:
         """Hand over every output finished since the last collection —
