@@ -1,6 +1,6 @@
 """Public paged-attention wrappers: GQA grouping, normalization, dtypes.
 
-Three entry points, one streaming kernel body (``kernel.py``):
+Three entry points over the streaming kernels of ``kernel.py``:
 
 * :func:`paged_attention_decode`  — one token per slot against the pooled
   KV blocks (global causal and windowed-ring layouts: both reduce to
@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.paged_attention.kernel import (
-    dense_attention_kernel, paged_attention_kernel,
+    dense_attention_kernel, paged_attention_kernel, paged_attention_kernel_prefill,
 )
 
 
@@ -57,8 +57,7 @@ def paged_attention_decode(
     o, _, l = paged_attention_kernel(
         qg, k_pool, v_pool, jnp.asarray(table, jnp.int32),
         jnp.asarray(kv_len, jnp.int32), k_scale, v_scale,
-        scale=hd ** -0.5, causal=False,
-        q_len=1, softcap=softcap, interpret=interpret,
+        scale=hd ** -0.5, softcap=softcap, interpret=interpret,
     )
     return _normalize(o, l).reshape(b, h, hd).astype(q.dtype)
 
@@ -86,11 +85,10 @@ def paged_attention_prefill(
     g = h // kvh
     qd = jnp.float32 if k_pool.dtype == jnp.int8 else k_pool.dtype
     qg = q.astype(qd).reshape(b, kvh, g * s, hd)
-    o, _, l = paged_attention_kernel(
+    o, _, l = paged_attention_kernel_prefill(
         qg, k_pool, v_pool, jnp.asarray(table, jnp.int32),
         jnp.asarray(start, jnp.int32), k_scale, v_scale,
-        scale=hd ** -0.5, causal=True,
-        q_len=s, softcap=softcap, interpret=interpret,
+        scale=hd ** -0.5, q_len=s, softcap=softcap, interpret=interpret,
     )
     return _normalize(o, l).reshape(b, h, s, hd).astype(q.dtype)
 

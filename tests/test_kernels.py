@@ -109,17 +109,31 @@ def _paged_setup(rng, b, kvh, g, hd, bs, w, n_blocks):
     return q, kp, vp, table
 
 
+def _int8_pool(rng, kp, vp):
+    """Random int8 pools with per-KV-head scales, like ``QuantPagedKVCache``."""
+    kvh = kp.shape[1]
+    kq, vq = (jnp.asarray(rng.integers(-127, 128, x.shape), jnp.int8) for x in (kp, vp))
+    ks, vs = (jnp.asarray(rng.uniform(0.005, 0.05, kvh), jnp.float32) for _ in range(2))
+    return kq, vq, ks, vs
+
+
 @pytest.mark.parametrize("g", [1, 2, 4])
 @pytest.mark.parametrize("softcap", [0.0, 5.0])
-def test_paged_decode_kernel_vs_ref(rng, g, softcap):
+@pytest.mark.parametrize("pool", ["f32", "int8"])
+def test_paged_decode_kernel_vs_ref(rng, g, softcap, pool):
     """Streamed decode vs the gathered-view oracle; one batch row per
-    kv_len boundary: empty, single token, exact block edge, one past it,
-    and the full table extent."""
+    kv_len boundary: empty (a slot still prefilling), single token, exact
+    block edge, one past it, and the full table extent.  The int8 pool is
+    checked against the oracle on the pool dequantized by its per-head
+    scales."""
     kvh, hd, bs, w = 2, 16, 4, 3
     kv_len = jnp.asarray([0, 1, bs, bs + 1, w * bs], jnp.int32)
     q, kp, vp, table = _paged_setup(rng, kv_len.shape[0], kvh, g, hd, bs, w, 16)
-    got = paged_attention_decode(q, kp, vp, table, kv_len, softcap=softcap)
-    want = paged_decode_ref(q, kp, vp, table, kv_len, softcap=softcap)
+    ks = vs = None
+    if pool == "int8":
+        kp, vp, ks, vs = _int8_pool(rng, kp, vp)
+    got = paged_attention_decode(q, kp, vp, table, kv_len, ks, vs, softcap=softcap)
+    want = paged_decode_ref(q, kp, vp, table, kv_len, softcap=softcap, k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
@@ -172,10 +186,12 @@ def test_dense_decode_kernel_vs_ref(rng):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
-def test_paged_decode_kernel_bf16(rng):
-    kvh, g, hd, bs, w = 2, 2, 16, 4, 3
-    kv_len = jnp.asarray([3, 9], jnp.int32)
-    q, kp, vp, table = _paged_setup(rng, 2, kvh, g, hd, bs, w, 8)
+@pytest.mark.parametrize("g", [1, 2, 4])
+@pytest.mark.parametrize("kv_len", [[3, 9], [0, 1, 4, 12]], ids=["mid", "edges"])
+def test_paged_decode_kernel_bf16(rng, g, kv_len):
+    kvh, hd, bs, w = 2, 16, 4, 3
+    kv_len = jnp.asarray(kv_len, jnp.int32)
+    q, kp, vp, table = _paged_setup(rng, kv_len.shape[0], kvh, g, hd, bs, w, 8)
     q, kp, vp = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
     got = paged_attention_decode(q, kp, vp, table, kv_len)
     assert got.dtype == jnp.bfloat16

@@ -13,6 +13,7 @@ only one process may load the TPU library, and test workers import
 every test file.  All such compiles live in this one file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +73,24 @@ def test_paged_decode_compiles(one_chip, dtype):
     _compile(functools.partial(paged_attention_decode, interpret=False), one_chip,
              ((B, H, HD), jnp.bfloat16), *pools, ((B, W), jnp.int32), ((B,), jnp.int32),
              *scales)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8], ids=["bf16", "int8"])
+def test_paged_decode_compiles_at_longdoc_shapes(one_chip, dtype):
+    """The benchmark cell stablelm-1.6b.longdoc's decode: 3 slots of a
+    196-block table over a 589-block pool.  The decode kernel's custom
+    call must keep the name ``paged_attention_kernel``, by which
+    bench/names.py finds it in the trace for the decode roofline."""
+    from repro.kernels.paged_attention import paged_attention_decode
+
+    b, w, n_blocks = 3, 196, 589
+    pool = ((n_blocks, H, BS, HD), dtype)
+    scales = [((H,), jnp.float32)] * 2 if dtype == jnp.int8 else []
+    compiled = _compile(functools.partial(paged_attention_decode, interpret=False), one_chip,
+                        ((b, H, HD), jnp.bfloat16), pool, pool, ((b, w), jnp.int32),
+                        ((b,), jnp.int32), *scales)
+    calls = re.findall(r"%(\S+) = .*custom_call_target=\"tpu_custom_call\"", compiled.as_text())
+    assert len(calls) == 1 and re.search("paged_attention_kernel", calls[0]), calls
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8], ids=["bf16", "int8"])
