@@ -169,12 +169,13 @@ def compare(cell: Cell, seed: int, run: Run,
     recs = check.sample(run.due_in_window(), seed, ck["min_tokens"], ck["max_requests"])
     numbers = {}
     if recs:
-        w = weights.make(cell.config, seed)
+        w = weights.make(cell.family, cell.config, seed)
         items = [(r.prompt, r.served) for r in recs]
-        g = check.gaps(cell.config, w, items, ck["seq_len"], ck["gen_len"], ck["batch"],
-                       control)
+        cols = {}
+        g = check.gaps(cell.family, cell.config, w, items, ck["seq_len"], ck["gen_len"],
+                       ck["batch"], control, cols)
         del w
-        read = check.readings(g)
+        read = check.readings(cell.family, g, cols)
         numbers = {name: {"value": read[name], "limit": lim} for name, lim in ck["limits"].items()}
     numbers["tokens_compared"] = {"value": sum(len(r.served) for r in recs),
                                   "limit": ck["min_tokens"]}

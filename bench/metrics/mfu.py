@@ -11,4 +11,4 @@ def read(r):
     if not ctx:
         return None
     peak = r.peak["bf16_flops"] * r.n_devices
-    return 100.0 * work.model_flops(r.cfg, ctx) / (r.trace.window_s * peak)
+    return 100.0 * work.model_flops(r.cell.family, r.cfg, ctx) / (r.trace.window_s * peak)

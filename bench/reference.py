@@ -1,16 +1,18 @@
-"""The plain reference: the configuration's forward pass in float32, in
+"""The plain reference: a configuration's forward pass in float32, in
 straightforward ``jax.numpy``, with no kernel, cache, paging or batching
 of the program's.  It imports nothing of the program: its weights come
 from ``bench.weights`` (the same seeded values the engine serves), and
-its equations from the configuration file.
+its equations from the configuration's family
+(``bench/families/<model_type>.py``), whose ``forward`` may use the
+shared pieces here:
 
-* attention: global causal GQA, rotary on the first
+* ``attention``: global causal GQA, rotary on the first
   ``partial_rotary_factor`` of each head as interleaved pairs;
-* FFN: SiLU-gated MLP;
-* norms: LayerNorm or RMSNorm in float32.
+* ``norm``: LayerNorm or RMSNorm in float32;
+* ``mm``: a weight GEMM, in float32 or the control's.
 
 Every matmul runs at ``highest`` precision.  ``control`` names a
-control: every weight GEMM (projections, MLP, LM head) with weights
+control: every weight GEMM (projections, FFN, LM head) with weights
 scaled per output column and activations per row into ``"int8"``
 (symmetric, round to nearest) or ``"fp8"`` (float8 e4m3), products
 accumulated in float32: the steps below the bf16 the configuration
@@ -19,6 +21,7 @@ states.
 from __future__ import annotations
 
 import functools
+import json
 from typing import Dict, Optional
 
 import jax
@@ -45,7 +48,7 @@ def _q(x, axis, control):
     raise ValueError(f"unknown control {control!r}; one of {CONTROLS}")
 
 
-def _mm(x, w, control):
+def mm(x, w, control):
     """x [..., d_in] @ w [d_in, d_out] in float32, or the control's."""
     w = w.astype(F32)
     if control is None:
@@ -55,7 +58,7 @@ def _mm(x, w, control):
     return (xq @ wq) * xs * ws
 
 
-def _norm(p, x, kind, eps):
+def norm(p, x, kind, eps):
     if kind == "rmsnorm":
         y = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
     else:
@@ -65,7 +68,7 @@ def _norm(p, x, kind, eps):
     return y + p["bias"].astype(F32) if "bias" in p else y
 
 
-def _rope(x, pct, theta):
+def rope(x, pct, theta):
     """x [B, S, H, hd]; interleaved pairs over the first ``pct`` of hd."""
     hd = x.shape[-1]
     rot = int(hd * pct) // 2 * 2
@@ -77,55 +80,40 @@ def _rope(x, pct, theta):
     return jnp.concatenate([y, x[..., rot:]], -1)
 
 
-def _attention(cfg, p, h, control):
+def attention(cfg, p, h, control):
+    """Global causal GQA over ``h [B, S, D]`` with the projections ``p``
+    (``wq``, ``wk``, ``wv``, ``wo``)."""
     b, s, _ = h.shape
     nh, kvh, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    q = _mm(h, p["wq"]["w"], control).reshape(b, s, nh, hd)
-    k = _mm(h, p["wk"]["w"], control).reshape(b, s, kvh, hd)
-    v = _mm(h, p["wv"]["w"], control).reshape(b, s, kvh, hd)
-    q = _rope(q, cfg["partial_rotary_factor"], cfg["rope_theta"])
-    k = _rope(k, cfg["partial_rotary_factor"], cfg["rope_theta"])
+    q = mm(h, p["wq"]["w"], control).reshape(b, s, nh, hd)
+    k = mm(h, p["wk"]["w"], control).reshape(b, s, kvh, hd)
+    v = mm(h, p["wv"]["w"], control).reshape(b, s, kvh, hd)
+    q = rope(q, cfg["partial_rotary_factor"], cfg["rope_theta"])
+    k = rope(k, cfg["partial_rotary_factor"], cfg["rope_theta"])
     q = q.reshape(b, s, kvh, nh // kvh, hd)
     scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k) * hd ** -0.5
     causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
     scores = jnp.where(causal, scores, -jnp.inf)
     o = jnp.einsum("bkgqs,bskd->bqkgd", jax.nn.softmax(scores, -1), v)
-    return _mm(o.reshape(b, s, nh * hd), p["wo"]["w"], control)
+    return mm(o.reshape(b, s, nh * hd), p["wo"]["w"], control)
 
 
-def _ffn(p, h, control):
-    up, gate = _mm(h, p["up"]["w"], control), _mm(h, p["gate"]["w"], control)
-    return _mm(jax.nn.silu(gate) * up, p["down"]["w"], control)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg_items", "control"))
-def _logits(w, tokens, positions, cfg_items, control):
-    cfg = dict(cfg_items)
+@functools.partial(jax.jit, static_argnames=("forward", "cfg_json", "control"))
+def _forward(w, tokens, positions, forward, cfg_json, control):
     with jax.default_matmul_precision("highest"):
-        x = w["embedding"]["table"].astype(F32)[tokens]
-
-        def layer(x, p):
-            x = x + _attention(cfg, p["core"], _norm(p["pre_norm"], x, cfg["norm"], cfg["norm_eps"]), control)
-            x = x + _ffn(p["mlp"], _norm(p["post_norm"], x, cfg["norm"], cfg["norm_eps"]), control)
-            return x, None
-
-        x, _ = jax.lax.scan(layer, x, w["units"]["slot0"])
-        x = jnp.take_along_axis(x, positions[..., None], axis=1)  # [B, G, D]
-        x = _norm(w["final_norm"], x, cfg["norm"], cfg["norm_eps"])
-        head = (w["embedding"]["table"].T if cfg["tie_word_embeddings"]
-                else w["head"]["w"])
-        return _mm(x, head, control)
+        return forward(json.loads(cfg_json), w, tokens, positions, control)
 
 
-_SHAPE_KEYS = ("num_hidden_layers", "hidden_size", "num_attention_heads",
-               "num_key_value_heads", "head_dim", "vocab_size", "norm", "norm_eps",
-               "partial_rotary_factor", "rope_theta", "tie_word_embeddings")
-
-
-def logits(cfg: Dict, w, tokens: np.ndarray, positions: np.ndarray,
-           control: Optional[str] = None) -> jax.Array:
+def logits(family, cfg: Dict, w, tokens: np.ndarray, positions: np.ndarray,
+           control: Optional[str] = None,
+           columns: Optional[Dict[str, jax.Array]] = None) -> jax.Array:
     """Float32 logits ``[B, G, V]`` at ``positions [B, G]`` of the causal
-    forward over ``tokens [B, S]``; with ``control``, that control's."""
-    items = tuple((k, cfg.get(k)) for k in _SHAPE_KEYS)
-    return _logits(w, jnp.asarray(tokens, jnp.int32), jnp.asarray(positions, jnp.int32),
-                   cfg_items=items, control=control)
+    forward over ``tokens [B, S]`` by the family's equations; with
+    ``control``, that control's.  ``columns``, where given, receives the
+    family's named per-position columns ``[B, G]`` beside the logits."""
+    out, cols = _forward(w, jnp.asarray(tokens, jnp.int32), jnp.asarray(positions, jnp.int32),
+                         forward=family.forward, cfg_json=json.dumps(cfg, sort_keys=True),
+                         control=control)
+    if columns is not None:
+        columns.update(cols)
+    return out

@@ -53,10 +53,10 @@ def main(argv=None):
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     dev = SingleDeviceSharding(topo.devices[0])
     put = lambda t: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev), t)
-    model = Model(system.arch(cell.config))
+    model = Model(system.arch(cell.family, cell.config))
     model = dataclasses.replace(model, opts=dataclasses.replace(
         model.opts, attn_impl=cell.config["serving"]["attn_impl"]))
-    params = put(weights.shapes(cell.config))
+    params = put(weights.shapes(cell.family, cell.config))
     states = put(jax.eval_shape(lambda: model.init_decode_state(b, eng["max_len"], paged=(nblk, bs))))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=dev)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=dev)

@@ -1,7 +1,7 @@
 """The system under test, built from a cell's files: the model from the
-repository's registry (checked against the configuration file), the
-benchmark's own seeded weights, and a ``ServeFrontend`` over a
-``ServeEngine`` with the configuration's serving options."""
+repository's registry (checked against the configuration file and its
+family), the benchmark's own seeded weights, and a ``ServeFrontend`` over
+a ``ServeEngine`` with the configuration's serving options."""
 from __future__ import annotations
 
 from typing import Any, Dict, Tuple
@@ -11,14 +11,14 @@ import jax
 from bench import weights
 from bench.spec import Cell
 
-# configuration-file key -> how the registry's ArchConfig states it
-_SHAPE_KEYS = {
+# configuration-file key -> how the registry's ArchConfig states it, for
+# every family; a family's ARCH_KEYS add its own
+_ARCH_KEYS = {
     "num_hidden_layers": lambda a: a.n_layers,
     "hidden_size": lambda a: a.d_model,
     "num_attention_heads": lambda a: a.n_heads,
     "num_key_value_heads": lambda a: a.n_kv_heads,
     "head_dim": lambda a: a.head_dim,
-    "intermediate_size": lambda a: a.d_ff,
     "vocab_size": lambda a: a.vocab,
     "norm": lambda a: a.norm,
     "norm_eps": lambda a: a.norm_eps,
@@ -26,24 +26,21 @@ _SHAPE_KEYS = {
     "rope_theta": lambda a: a.rope_theta,
     "tie_word_embeddings": lambda a: a.tie_embeddings,
     "use_qkv_bias": lambda a: a.qkv_bias,
-    "hidden_act": lambda a: {"swiglu": "silu"}.get(a.act, a.act),
 }
 
 
-def arch(config: Dict[str, Any], arch_cfg=None):
-    """The registry's ArchConfig for the file, refused where they differ."""
+def arch(family, config: Dict[str, Any], arch_cfg=None):
+    """The registry's ArchConfig for the file, refused where they differ
+    or where the family does not cover it."""
     if arch_cfg is None:
         from repro.configs import get_arch
 
         arch_cfg = get_arch(config["arch"])
-    for key, get in _SHAPE_KEYS.items():
+    for key, get in {**_ARCH_KEYS, **family.ARCH_KEYS}.items():
         if config.get(key) != get(arch_cfg):
             raise ValueError(f"{config['name']}: {key}={config.get(key)!r} in the "
                              f"configuration file, {get(arch_cfg)!r} in the registry")
-    if (arch_cfg.logit_softcap or arch_cfg.window or arch_cfg.moe
-            or set(arch_cfg.layer_kinds) != {"attn"}):
-        raise ValueError(f"{config['name']}: the reference covers global attention "
-                         "and dense FFNs only")
+    family.check_arch(arch_cfg)
     return arch_cfg
 
 
@@ -53,12 +50,12 @@ def build(cell: Cell, seed: int, arch_cfg=None) -> Tuple[Any, Any, Any]:
     from repro.serve import FrontendConfig, ServeConfig, ServeEngine, ServeFrontend
     from repro.serve.sampling import GREEDY
 
-    cfg = arch(cell.config, arch_cfg)
+    cfg = arch(cell.family, cell.config, arch_cfg)
     serving = cell.config["serving"]
     if serving["sampler"] != "greedy" or serving.get("eos_id") is not None:
         raise ValueError("the comparison with the reference needs greedy tokens, no EOS")
     model = Model(cfg)  # exact mode
-    params = weights.make(cell.config, seed)
+    params = weights.make(cell.family, cell.config, seed)
     want = jax.eval_shape(lambda: model.serving_params(model.init(jax.random.PRNGKey(0))))
     got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
     if jax.tree.structure(want) != jax.tree.structure(got) or jax.tree.leaves(want) != jax.tree.leaves(got):

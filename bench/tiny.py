@@ -1,6 +1,7 @@
 """A cell cut to a size a CPU test can run: a copy of the benchmark's
 files in a scratch root, with the cell's configuration at the registry's
-``reduced()`` widths and its traffic and engine scaled down to match.
+``reduced()`` widths (the keys every family shares here, the rest by its
+family's ``tiny``) and its traffic and engine scaled down to match.
 Only tests use it; the chip never runs these sizes."""
 from __future__ import annotations
 
@@ -9,7 +10,7 @@ import shutil
 from pathlib import Path
 from typing import Tuple
 
-from bench.spec import ROOT, load_json
+from bench.spec import ROOT, load_family, load_json
 
 
 def _dump(path: Path, obj) -> None:
@@ -39,7 +40,8 @@ def make_root(dest: Path, cell: str, n_layers: int = 2, attn_impl: str = "flash"
     a = get_arch(conf["arch"]).reduced(n_layers=n_layers)
     conf.update(num_hidden_layers=a.n_layers, hidden_size=a.d_model,
                 num_attention_heads=a.n_heads, num_key_value_heads=a.n_kv_heads,
-                head_dim=a.head_dim, intermediate_size=a.d_ff, vocab_size=a.vocab)
+                head_dim=a.head_dim, vocab_size=a.vocab)
+    conf.update(load_family(dest, conf).tiny(conf, a))
     conf["serving"]["attn_impl"] = attn_impl
     _dump(cfg_path, conf)
     tr_path = dest / "bench" / "traffic" / f"{entry['traffic']}.json"

@@ -7,7 +7,9 @@ padded table width, the kernel's grid and a prefill row's padding earn
 nothing, so the count is the same whatever implements attention.  Model
 FLOPs are 2 x (non-embedding parameters a token touches + the LM head)
 per token processed, plus causal attention's 4 x layers x context x
-q_dim.
+q_dim.  Attention's projections and the LM head are counted here; the
+configuration's family (``bench/families/<model_type>.py``) counts the
+rest of the weights a token touches.
 """
 from __future__ import annotations
 
@@ -47,18 +49,18 @@ def prefill_attn(cfg: Dict, rows: Iterable[Tuple[int, int]]) -> Tuple[float, flo
     return L * flops, L * nbytes
 
 
-def params_per_token(cfg: Dict) -> float:
+def params_per_token(family, cfg: Dict) -> float:
     """Non-embedding weights a token multiplies by, plus the LM head."""
     L, q_dim, kv_dim, d = _dims(cfg)
-    per_layer = d * q_dim + 2 * d * kv_dim + q_dim * d + 3 * d * cfg["intermediate_size"]
-    return float(L * per_layer + d * cfg["vocab_size"])
+    attn = d * q_dim + 2 * d * kv_dim + q_dim * d
+    return float(L * attn + family.params_per_token(cfg) + d * cfg["vocab_size"])
 
 
-def model_flops(cfg: Dict, contexts: Iterable[int]) -> float:
+def model_flops(family, cfg: Dict, contexts: Iterable[int]) -> float:
     """Model FLOPs of the tokens processed, one entry per token with the
     number of keys it attends to (its own position included)."""
     L, q_dim, _, _ = _dims(cfg)
-    per_tok = 2.0 * params_per_token(cfg)
+    per_tok = 2.0 * params_per_token(family, cfg)
     n = total = 0.0
     for ctx in contexts:
         n += 1

@@ -44,6 +44,8 @@ def test_every_file_is_found_by_name(bench):
         with open(os.path.join(ROOT, c["file"])) as f:
             conf = json.load(f)
         assert conf["name"] == c["name"] and conf["reduced"] == c["reduced"]
+        family = os.path.join(ROOT, "bench", "families", conf["model_type"] + ".py")
+        assert os.path.isfile(family), (c["name"], family)
     for w in bench["workloads"]:
         assert w["chips"] in (1, 4)
         for path in (("workloads", w["name"]), ("traffic", w["traffic"])):

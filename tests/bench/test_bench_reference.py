@@ -40,9 +40,10 @@ def _serve(cell, arch, seed, n=16):
 
 def _gap(cell, seed, items):
     ck = cell.workload["check"]
-    w = weights.make(cell.config, seed)
-    return check.readings(check.gaps(cell.config, w, items, ck["seq_len"], ck["gen_len"],
-                                     ck["batch"]))["mean_logit_gap"]
+    w = weights.make(cell.family, cell.config, seed)
+    return check.readings(cell.family, check.gaps(cell.family, cell.config, w, items,
+                                                  ck["seq_len"], ck["gen_len"],
+                                                  ck["batch"]))["mean_logit_gap"]
 
 
 def test_reference_matches_the_program_forward(tiny_cell):
@@ -53,11 +54,11 @@ def test_reference_matches_the_program_forward(tiny_cell):
     from repro.serve.prefill import _drop_free
 
     cell, arch = tiny_cell
-    w = weights.make(cell.config, 7)
+    w = weights.make(cell.family, cell.config, 7)
     toks = np.random.default_rng(7).integers(0, arch.vocab, (2, 40)).astype(np.int32)
     got, _ = _drop_free(Model(arch)).prefill(w, {"tokens": toks})
     pos = np.broadcast_to(np.arange(40), (2, 40))
-    ref = np.asarray(reference.logits(cell.config, w, toks, pos))
+    ref = np.asarray(reference.logits(cell.family, cell.config, w, toks, pos))
     err = np.linalg.norm(np.asarray(got) - ref) / np.linalg.norm(ref)
     assert err < 2e-2
 

@@ -10,7 +10,7 @@ import pytest
 ROOT = os.path.join(os.path.dirname(__file__), "..", "..")
 sys.path.insert(0, ROOT)
 
-from bench import work  # noqa: E402
+from bench import spec, work  # noqa: E402
 from bench.probe import DecodeCall, PrefillCall  # noqa: E402
 
 
@@ -20,6 +20,7 @@ def cfg(name):
 
 
 STABLELM = cfg("stablelm-1.6b")
+DENSE = spec.load_family(spec.ROOT, STABLELM)
 V5E = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
 
 
@@ -45,9 +46,9 @@ def test_prefill_attention_counts():
 
 def test_model_flops_per_token():
     # stablelm: 24 x (4 x 2048^2 + 3 x 2048 x 5632) + 2048 x 100352
-    assert work.params_per_token(STABLELM) == 1_438_646_272
+    assert work.params_per_token(DENSE, STABLELM) == 1_438_646_272
     # plus causal attention: 4 x 24 layers x 10 keys x q_dim 2048
-    assert work.model_flops(STABLELM, [10]) == 2 * 1_438_646_272 + 4 * 24 * 10 * 2048
+    assert work.model_flops(DENSE, STABLELM, [10]) == 2 * 1_438_646_272 + 4 * 24 * 10 * 2048
 
 
 def test_roofline_bound():
@@ -59,7 +60,8 @@ def test_roofline_bound():
 def _readings(cfg_, calls_prefill, calls_decode, max_slots):
     trace = types.SimpleNamespace(op_seconds=lambda *a: 0.01, program_seconds=lambda *a: 0.1,
                                   window_s=1.0)
-    return types.SimpleNamespace(cfg=cfg_, peak=V5E, n_devices=1, trace=trace,
+    return types.SimpleNamespace(cfg=cfg_, cell=types.SimpleNamespace(family=DENSE),
+                                 peak=V5E, n_devices=1, trace=trace,
                                  max_slots=max_slots,
                                  traced_calls=lambda: (calls_prefill, calls_decode))
 
